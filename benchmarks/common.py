@@ -13,12 +13,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ModelConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api
 from repro.serving.engine import Engine
 from repro.training import checkpoint as CK
 from repro.training import data as D
 from repro.training import optimizer as OPT
 from repro.training import train_loop as TL
+
+enable_compile_cache(os.path.join(os.path.dirname(__file__), ".."))
 
 CKPT_DIR = os.path.join(os.path.dirname(__file__), "..", "results",
                         "tiny_olap_ckpt")

@@ -27,10 +27,14 @@ every device's decode step before blocking on any result.  Two axes:
 Outputs of the device-parallel scheduler runs are asserted
 **byte-identical** to serial single-device private-engine runs.
 
-Needs >= ``NDEV`` jax devices; when the current process has fewer (the
-usual laptop/CI case) it re-runs itself in a subprocess with
-``XLA_FLAGS=--xla_force_host_platform_device_count=4`` — the "fake
-multi-device recipe" documented in the README.
+Needs >= ``NDEV`` jax devices.  On an accelerator host that has them
+it runs in-process.  Under ``JAX_PLATFORMS=cpu`` without a forced
+device count (the usual laptop/CI case) it re-runs itself in a
+subprocess with ``XLA_FLAGS=--xla_force_host_platform_device_count=4``
+— the "fake multi-device recipe" documented in the README.  That
+choice is made from the environment alone, before JAX starts a
+backend, so a process that holds an accelerator never starts a child
+that needs it.
 """
 from __future__ import annotations
 
@@ -56,25 +60,25 @@ W8 = dict(name="w8", wbits=8, quant_method="absmax")
 # multi-device bootstrap: re-exec with forced host devices when needed
 # ---------------------------------------------------------------------------
 
+def _wants_forced_cpu_devices() -> bool:
+    """True for the forced-CPU recipe: the CPU platform is pinned and
+    no device count is forced yet.  Reads the environment only, so it
+    is safe before JAX initializes a backend."""
+    return (os.environ.get("JAX_PLATFORMS", "") == "cpu"
+            and "xla_force_host_platform_device_count"
+            not in os.environ.get("XLA_FLAGS", ""))
+
+
 def _respawn(csv, *, smoke: bool, json_path: str | None) -> dict:
-    """Re-run this benchmark in a subprocess whose XLA platform is
-    forced to NDEV CPU devices (jax device count is fixed at first
-    backend init, so the current process cannot grow devices).  The
-    marker env var makes a second respawn impossible: if the forced
-    child still comes up short of devices we fail loudly instead of
-    forking forever."""
-    if os.environ.get("_DEVICE_PARALLEL_RESPAWNED"):
-        raise RuntimeError(
-            f"respawned child still has fewer than {NDEV} devices — "
-            "the forced CPU platform did not take effect")
+    """Re-run this benchmark in a subprocess whose CPU platform is
+    forced to NDEV devices (jax device count is fixed at first backend
+    init, so the current process cannot grow devices).  The child
+    inherits ``JAX_PLATFORMS=cpu`` and gets the forced count in
+    ``XLA_FLAGS``, so it cannot respawn again."""
     env = dict(os.environ)
-    env["_DEVICE_PARALLEL_RESPAWNED"] = "1"
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={NDEV}"
                         ).strip()
-    # the flag only multiplies the CPU host platform: pin the child to
-    # it even when the parent was aimed at an accelerator
-    env["JAX_PLATFORMS"] = "cpu"
     out = json_path or os.path.join(tempfile.mkdtemp(), "device_parallel.json")
     cmd = [sys.executable, os.path.abspath(__file__), "--json", out]
     if smoke:
@@ -195,6 +199,7 @@ def _run(csv, *, smoke: bool, json_path: str | None) -> dict:
     from benchmarks.common import Csv, load_model
     from repro.core.compressed import param_bytes
     from repro.serving.scheduler import slot_state_bytes
+    from repro.launch.mesh import make_mesh
 
     csv = csv or Csv()
     cfg, params, tok = load_model()
@@ -238,8 +243,7 @@ def _run(csv, *, smoke: bool, json_path: str | None) -> dict:
     # --- axis 2: TP base vs compressed replicas on one mesh -----------
     # budget where the uncompressed model fits NO single device: the
     # pool admits it tensor-parallel; int8 replicas still place 1:1
-    mesh = jax.make_mesh((1, NDEV), ("data", "model"),
-                         devices=devices)
+    mesh = make_mesh((1, NDEV), ("data", "model"), devices=devices)
     tp_budget = int(0.8 * base_entry)
     c = run_cell(params, cfg, tok, None, tp_budget, n_tenants, n_rows,
                  mesh=mesh, optimize=False)
@@ -295,9 +299,13 @@ def _run(csv, *, smoke: bool, json_path: str | None) -> dict:
 
 def main(csv=None, *, smoke: bool = False,
          json_path: str | None = None) -> dict:
+    if _wants_forced_cpu_devices():
+        return _respawn(csv, smoke=smoke, json_path=json_path)
     import jax
     if jax.device_count() < NDEV:
-        return _respawn(csv, smoke=smoke, json_path=json_path)
+        raise RuntimeError(
+            f"needs {NDEV} devices, found {jax.devices()}; on a CPU host "
+            "run with JAX_PLATFORMS=cpu for the forced-device recipe")
     return _run(csv, smoke=smoke, json_path=json_path)
 
 

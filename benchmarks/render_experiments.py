@@ -139,7 +139,8 @@ def _latest(pattern):
 
 
 def readme_results_table() -> str:
-    lines = ["| benchmark | cell | rows/s | v5e rows/s | notes |",
+    lines = ["| benchmark | cell | CPU rows/s | v5e rows/s: roofline "
+             "projection, not measured | notes |",
              "|---|---|---|---|---|"]
     n = 0
     serving = _latest("serving")
@@ -186,9 +187,11 @@ def readme_results_table() -> str:
         return ("_No `results/BENCH_*.json` artifacts found — run the "
                 "benchmarks with `--json` first (see below)._")
     lines.append("")
-    lines.append("_CPU `--smoke` numbers from this container; `v5e` is "
-                 "the roofline projection on the TPU target (aggregate "
-                 "over resident engines).  Regenerate: run the four "
+    lines.append("_`CPU rows/s` is host wall clock of the `--smoke` runs on "
+                 "a CPU, not a device metric.  The v5e column is a "
+                 "roofline projection from peak constants (aggregate over "
+                 "resident engines); no chip measured it.  Regenerate: run "
+                 "the four "
                  "benchmarks with `--json results/BENCH_<name>.json`, "
                  "then `python benchmarks/render_experiments.py "
                  "--readme`._")

@@ -47,10 +47,12 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
+import jax.extend.core as jex_core
 
 from repro.analysis.diagnostics import Diagnostic
 
-CALLBACK_PRIMS = ("debug_callback", "pure_callback", "io_callback",
+CALLBACK_PRIMS = ("debug_callback", "debug_print", "pure_callback",
+                  "io_callback",
                   "callback", "host_callback_call", "outside_call")
 
 LOW_PRECISION = ("bfloat16", "float16")
@@ -61,10 +63,9 @@ LOW_PRECISION = ("bfloat16", "float16")
 # ---------------------------------------------------------------------------
 
 def _subjaxprs(v):
-    core = jax.core
-    if isinstance(v, core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         yield v.jaxpr
-    elif isinstance(v, core.Jaxpr):
+    elif isinstance(v, jex_core.Jaxpr):
         yield v
     elif isinstance(v, (list, tuple)):
         for x in v:
@@ -74,7 +75,7 @@ def _subjaxprs(v):
 def iter_eqns(jaxpr):
     """Every equation of a (closed) jaxpr, recursing into sub-jaxprs
     (pjit bodies, scan/while/cond branches, vmapped calls)."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jex_core.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     for eqn in jaxpr.eqns:
         yield eqn
@@ -185,9 +186,9 @@ def audit_promotions(name: str, closed) -> List[Diagnostic]:
     """Strong f32 scalar literals silently widening bf16/f16 math."""
     out = []
     for eqn in iter_eqns(closed):
-        lits = [v for v in eqn.invars if isinstance(v, jax.core.Literal)]
+        lits = [v for v in eqn.invars if isinstance(v, jex_core.Literal)]
         arrs = [v for v in eqn.invars
-                if not isinstance(v, jax.core.Literal)]
+                if not isinstance(v, jex_core.Literal)]
         if not (lits and arrs and eqn.outvars):
             continue
         strong_f32_lit = any(

@@ -335,7 +335,7 @@ def matmul(x: jax.Array, w) -> jax.Array:
                                      in_scale=w.in_scale)
         return _q_matmul_jnp(x, w)
     if isinstance(w, BlockSparseTensor):
-        if w.idx is not None and current_backend() == "pallas":
+        if current_backend() == "pallas":
             from repro.kernels import ops as kops
             return kops.block_sparse_matmul(x, w.w, w.idx, bs=w.bs)
         return jnp.einsum("...i,io->...o", x, w.w.astype(x.dtype),

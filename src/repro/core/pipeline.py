@@ -62,6 +62,15 @@ class Recipe:
     smooth_alpha: float = 0.0          # SmoothQuant (0 = off)
     quant_embed: bool = False
 
+    @property
+    def needs_hessian(self) -> bool:
+        """Whether compression reads the calibration Hessian (GPTQ
+        quantization or SparseGPT pruning); the others need only
+        channel norms and maxima."""
+        return ((self.wbits < 16 and self.quant_method == "gptq")
+                or (bool(self.sparsity or self.nm[1])
+                    and self.sparse_method == "sparsegpt"))
+
     def describe(self) -> str:
         parts = []
         if self.drop_units:
@@ -371,5 +380,6 @@ def _stack_q(items):
     if isinstance(first, BlockSparseTensor):
         return BlockSparseTensor(jnp.stack([it.w for it in items]),
                                  jnp.stack([it.mask for it in items]),
-                                 first.bs)
+                                 first.bs,
+                                 jnp.stack([it.idx for it in items]))
     return jnp.stack(items)

@@ -141,6 +141,9 @@ class SearchOutcome:
     candidates: List[Candidate]
     perf: Optional[Candidate]
     acc: Optional[Candidate]
+    # recipes that raised during compression or evaluation, with the
+    # exception as "Type: message" — never dropped silently
+    dropped: List[Tuple[str, str]] = field(default_factory=list)
 
     def table(self) -> str:
         rows = [f"{'recipe':24s} {'acc':>5s} {'tok':>5s} {'rows/s':>8s} "
@@ -159,6 +162,7 @@ class SearchOutcome:
                         f"{c.result.token_agreement:5.2f} "
                         f"{c.result.rows_per_s:8.2f} "
                         f"{c.result.bytes / 1e6:8.1f}{tag}")
+        rows += [f"{name:24s} dropped: {err}" for name, err in self.dropped]
         return "\n".join(rows)
 
 
@@ -168,11 +172,13 @@ def search(optimizer: InstanceOptimizer, eval_fn: Callable,
     """Compress with every recipe, evaluate, select Perf/Acc variants."""
     baseline = eval_fn(optimizer.params, optimizer.cfg)
     cands: List[Candidate] = []
+    dropped: List[Tuple[str, str]] = []
     for r in recipes:
         try:
             params2, cfg2, report = optimizer.apply(r)
             res = eval_fn(params2, cfg2)
         except Exception as e:  # a recipe inapplicable to this family
+            dropped.append((r.name, f"{type(e).__name__}: {e}"))
             continue
         cands.append(Candidate(recipe=r, result=res, report=report,
                                params=params2 if keep_params else None,
@@ -187,4 +193,4 @@ def search(optimizer: InstanceOptimizer, eval_fn: Callable,
                                         c.result.token_agreement,
                                         -c.result.bytes))
     return SearchOutcome(baseline=baseline, candidates=cands, perf=perf,
-                         acc=acc)
+                         acc=acc, dropped=dropped)

@@ -65,5 +65,6 @@ def block_sparse_matmul_kernel(x, w, idx, *, bs: int, bm: int = 128,
         functools.partial(_kernel, nk=keep),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+        name="block_sparse_matmul",
         interpret=interpret,
     )(idx, x, w)

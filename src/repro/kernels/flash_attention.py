@@ -115,5 +115,6 @@ def flash_attention_kernel(q, k, v, *, group: int, causal: bool = True,
             pltpu.VMEM((bq, 1), jnp.float32),   # running denom
             pltpu.VMEM((bq, D), jnp.float32),   # running numerator
         ],
+        name="flash_attention",
         interpret=interpret,
     )(q, k, v)

@@ -3,8 +3,9 @@
 Shape plumbing lives here: flattening batch dims, padding to tile
 multiples, head/batch reshapes for attention, and the interpret-mode
 fallback so the kernels run (slowly, but bit-faithfully) on CPU for
-tests.  ``repro.core.compressed.matmul`` and the model layers call these
-when the active KernelBackend resolves to ``"pallas"``.
+tests; on a TPU the compiled kernels always run.
+``repro.core.compressed.matmul`` and the model layers call these when
+the active KernelBackend resolves to ``"pallas"``.
 """
 from __future__ import annotations
 
@@ -17,17 +18,14 @@ from repro.kernels.paged_attention import paged_attention_kernel
 from repro.kernels.quant_matmul import quant_matmul_kernel
 
 
-def _interpret_default(*arrays) -> bool:
-    """Interpret-mode default, resolved per call from the inputs' actual
-    devices — never cached: tests (and multi-backend processes) change the
-    effective platform after import, and under ``jit`` the inputs are
-    tracers so the live default backend is the right answer."""
-    for a in arrays:
-        try:
-            devs = a.devices()
-        except Exception:            # tracers / abstract values
-            continue
-        return not any(d.platform == "tpu" for d in devs)
+def _off_tpu() -> bool:
+    """Whether a kernel called with ``interpret=None`` runs off-TPU.
+
+    Resolved per call from the live default backend, never cached
+    (tests change the effective platform after import).  On a TPU
+    process this is always False: the compiled kernel runs, and an
+    input the TPU cannot take fails loudly instead of falling back to
+    the interpreter or the reference formula."""
     return jax.default_backend() != "tpu"
 
 
@@ -53,7 +51,7 @@ def quant_matmul(x, q, scale, *, group: int, in_scale=None,
     kernel under the Pallas interpreter (tests/test_kernels.py)."""
     K, N = q.shape
     if interpret is None:
-        if _interpret_default(x, q):
+        if _off_tpu():
             from repro.core.compressed import QTensor, _q_matmul_jnp
             return _q_matmul_jnp(x, QTensor(q, scale, 8, group, (K, N),
                                             in_scale))
@@ -63,10 +61,11 @@ def quant_matmul(x, q, scale, *, group: int, in_scale=None,
     x2 = x.reshape(-1, K)
     bm = 128 if x2.shape[0] >= 128 else 8
     x2, M = _pad_rows(x2, bm)
-    bk = 512 if K % 512 == 0 else K
-    while K % bk:
-        bk //= 2
-    bk = max(bk, group)
+    # K tile: whole groups whose [bk // group, bn] scale block has a
+    # multiple of 8 rows, else all of K — the TPU lowering refuses any
+    # other second-minor block size of the [K // group, N] scale array
+    tile = 8 * group * max(1, 512 // (8 * group))
+    bk = tile if K % tile == 0 else K
     y = quant_matmul_kernel(x2, q, scale, group=group, bm=bm, bk=bk,
                             bn=128 if N % 128 == 0 else N,
                             interpret=interpret)
@@ -81,7 +80,7 @@ def block_sparse_matmul(x, w, idx, *, bs: int, interpret=None):
     ``quant_matmul``); ``interpret=True`` runs the gather kernel under
     the interpreter."""
     if interpret is None:
-        if _interpret_default(x, w):
+        if _off_tpu():
             return jnp.einsum("...i,io->...o", x, w.astype(x.dtype),
                               preferred_element_type=jnp.float32
                               ).astype(x.dtype)
@@ -100,7 +99,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret=None):
     """q [B, S, H, D], k/v [B, T, Kh, D] -> [B, S, H, D] (GQA-aware)."""
     if interpret is None:
-        interpret = _interpret_default(q, k)
+        interpret = _off_tpu()
     B, S, H, D = q.shape
     _, T, Kh, _ = k.shape
     G = H // Kh
@@ -132,7 +131,7 @@ def paged_attention(q, k_pool, v_pool, tables, lengths, *,
     -> [S, 1, H, D].
     """
     if interpret is None:
-        interpret = _interpret_default(q, k_pool)
+        interpret = _off_tpu()
     S, one, H, D = q.shape
     assert one == 1, q.shape
     _, _, Kh, _ = k_pool.shape

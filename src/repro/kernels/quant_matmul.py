@@ -71,5 +71,6 @@ def quant_matmul_kernel(x, q, scale, *, group: int, bm: int = 128,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        name="quant_matmul",
         interpret=interpret,
     )(x, q, scale)

@@ -128,8 +128,6 @@ class Roofline:
 
 def analyze(compiled, chips: int, hlo_text: Optional[str] = None) -> Roofline:
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):
-        ca = ca[0]
     flops = float(ca.get("flops", 0.0))
     nbytes = float(ca.get("bytes accessed", 0.0))
     txt = hlo_text if hlo_text is not None else compiled.as_text()

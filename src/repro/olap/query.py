@@ -32,6 +32,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 import jax.numpy as jnp
 
 from repro.core.calibrate import CascadeCalibration, fit_confidence_threshold
+from repro.core.compressed import kernel_backend
 from repro.core.pipeline import InstanceOptimizer, Recipe
 from repro.core import policy as POL
 from repro.kernels.backend import normalize_backend
@@ -162,6 +163,8 @@ class IOLMSession:
         # seen (qsig, dsig) work with both counters unchanged
         self.recalibrations = 0       # full InstanceOptimizer runs
         self.cascade_fits = 0         # cascade threshold fits
+        # (qsig, recipe name, error) for every recipe a search dropped
+        self.dropped_recipes: List[Tuple[str, str, str]] = []
         self.log: List[str] = []
         self.pool = pool
         if pool is not None and (devices is not None or mesh is not None):
@@ -259,19 +262,27 @@ class IOLMSession:
             [self.tok.encode(p, bos=True) for p in sample],
             seq_len=max(16, max(len(p) + 2 for p in sample)))
         batch = {"tokens": jnp.asarray(toks)}
-        opt = InstanceOptimizer(self.params, self.cfg)
-        opt.run_calibration(batch)
         recipes = self.recipes or POL.default_recipe_space(self.cfg)
+        opt = InstanceOptimizer(self.params, self.cfg)
+        # the d_in x d_in Hessians are the costly statistic: gather them
+        # only for a recipe that reads them
+        opt.run_calibration(batch,
+                            hessian=any(r.needs_hessian for r in recipes))
         hold = prompts[self.calib_rows:
                        self.calib_rows + self.eval_rows] or sample
         htoks, hlens = self.tok.pad_batch(
             [self.tok.encode(p, bos=True) + [self.tok.SEP] for p in hold],
             seq_len=max(16, max(len(p) + 3 for p in hold)))
-        eval_fn = POL.make_agreement_eval(self.params, self.cfg,
-                                          jnp.asarray(htoks), max_new=12,
-                                          lengths=jnp.asarray(hlens))
-        outcome = POL.search(opt, eval_fn, recipes,
-                             acc_floor=self.acc_floor, keep_params=True)
+        # candidates are scored on the session's kernel backend
+        with kernel_backend(self.backend):
+            eval_fn = POL.make_agreement_eval(
+                self.params, self.cfg, jnp.asarray(htoks), max_new=12,
+                lengths=jnp.asarray(hlens))
+            outcome = POL.search(opt, eval_fn, recipes,
+                                 acc_floor=self.acc_floor, keep_params=True)
+        for name, err in outcome.dropped:
+            self.dropped_recipes.append((qsig, name, err))
+            self.log.append(f"[iolm] {qsig}: dropped {name}: {err}")
         pick = outcome.perf if self.objective == "perf" else outcome.acc
         if pick is None:  # nothing survived: identity model
             m = OptimizedModel(self.params, self.cfg, None,

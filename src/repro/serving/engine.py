@@ -79,6 +79,7 @@ import numpy as np
 
 from repro.core.compressed import kernel_backend
 from repro.kernels.backend import resolve_backend
+from repro.launch.mesh import auto_axes
 from repro.models import api
 from repro.serving.batcher import Batcher, Request, bucket_len
 from repro.serving.cache import PrefixCache, ResultCache
@@ -163,6 +164,9 @@ class Engine:
         # TPU, reference elsewhere) and scoped around every jit trace
         # site via kernel_backend() — no process-global flag.
         self.backend = resolve_backend(backend)
+        if mesh is not None:
+            # the sharding rules rely on GSPMD propagation (Auto axes)
+            mesh = auto_axes(mesh)
         self.device = device
         self.mesh = mesh
         self._cache_shardings = cache_shardings

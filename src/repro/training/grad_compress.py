@@ -77,7 +77,6 @@ def compressed_allreduce(grads, residual, *, axis: str, mesh):
     if n == 1:
         return grads, residual
 
-    from jax.experimental.shard_map import shard_map
 
     def body(g_tree, r_tree):
         pairs = jax.tree.map(
@@ -90,6 +89,6 @@ def compressed_allreduce(grads, residual, *, axis: str, mesh):
     # replicate in/out over all axes; internal collectives act on `axis`
     gspec = jax.tree.map(lambda _: P(), grads)
     rspec = jax.tree.map(lambda _: P(), residual)
-    fn = shard_map(body, mesh=mesh, in_specs=(gspec, rspec),
-                   out_specs=(gspec, rspec), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(gspec, rspec),
+                   out_specs=(gspec, rspec), check_vma=False)
     return fn(grads, residual)

@@ -68,11 +68,10 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x_mb, *,
             jnp.where(sid == S - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    from jax.experimental.shard_map import shard_map
     spec_p = jax.tree.map(lambda _: P(axis), params_stacked)
-    fn = shard_map(per_stage, mesh=mesh,
+    fn = jax.shard_map(per_stage, mesh=mesh,
                    in_specs=(spec_p, P()), out_specs=P(),
-                   check_rep=False)
+                   check_vma=False)
     return fn(params_stacked, x_mb)
 
 

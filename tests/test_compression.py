@@ -9,7 +9,8 @@ from repro.configs import registry
 from repro.core import quantize as Q
 from repro.core import sparsify as S
 from repro.core.calibrate import calibrate
-from repro.core.compressed import (QTensor, param_bytes, quantize_embed)
+from repro.core.compressed import (BlockSparseTensor, QTensor, param_bytes,
+                                   quantize_embed)
 from repro.core.pipeline import InstanceOptimizer, Recipe
 from repro.models import api
 
@@ -126,6 +127,22 @@ class TestPipeline:
         lg, _ = api.decode_step(p2, c2, cache, batch["tokens"][:, :1],
                                 jnp.zeros((2,), jnp.int32), max_len=64)
         assert not bool(jnp.any(jnp.isnan(lg.astype(jnp.float32))))
+
+    def test_stacked_block_sparse_keeps_gather_indices(self,
+                                                       reduced_models):
+        """Layer-stacked block-sparse weights carry their per-layer
+        gather indices: without them the pallas backend cannot run the
+        tile-skipping kernel."""
+        cfg, params = reduced_models["mistral-nemo-12b"]
+        p2, _, _ = InstanceOptimizer(params, cfg).apply(
+            Recipe(name="bs", block_bs=16, block_density=0.5))
+        leaves = [x for x in jax.tree_util.tree_leaves(
+            p2, is_leaf=lambda x: isinstance(x, BlockSparseTensor))
+            if isinstance(x, BlockSparseTensor)]
+        assert leaves and all(x.idx is not None for x in leaves)
+        stacked = [x for x in leaves if x.w.ndim == 3]
+        assert stacked and all(x.idx.shape[0] == x.w.shape[0]
+                               for x in stacked)
 
     def test_w8_preserves_argmax(self, reduced_models):
         cfg, params = reduced_models["mistral-nemo-12b"]

@@ -142,8 +142,6 @@ def test_small_mesh_train_step_lowers_with_collectives():
         txt = c.as_text()
         print("HAS_AR", "all-reduce" in txt)
         ca = c.cost_analysis()
-        if isinstance(ca, list):   # newer JAX: one dict per partition
-            ca = ca[0]
         print("FLOPS_OK", float(ca.get("flops", 0.0)) > 0)
     """)
     assert "HAS_AR True" in out

@@ -5,7 +5,7 @@ import numpy as np
 import pytest
 
 from repro.core import policy as POL
-from repro.core.pipeline import Recipe
+from repro.core.pipeline import InstanceOptimizer, Recipe
 from repro.models import api
 from repro.olap.query import IOLMSession, ModelCache, OptimizedModel
 
@@ -88,6 +88,37 @@ class TestOptimizeWorkflow:
         assert m.params is params                # the base model, unchanged
         # the fallback is cached like any other outcome
         assert sess._optimize("qsig1", PROMPTS) is m
+
+    def test_dropped_recipe_is_recorded(self, tiny, monkeypatch):
+        """A recipe that raises is reported with its exception — in the
+        search outcome and on the session — never dropped silently."""
+        apply = InstanceOptimizer.apply
+
+        def flaky(self, recipe):
+            if recipe.name == "boom":
+                raise ValueError("no such layer")
+            return apply(self, recipe)
+
+        monkeypatch.setattr(InstanceOptimizer, "apply", flaky)
+        sess = make_session(tiny, recipes=[W8, Recipe(name="boom", wbits=8)])
+        assert sess._optimize("qsig1", PROMPTS).recipe.name == "w8"
+        assert sess.dropped_recipes == [
+            ("qsig1", "boom", "ValueError: no such layer")]
+
+    @pytest.mark.parametrize("recipe,hessian", [
+        (W8, False), (Recipe(name="w8-gptq", wbits=8), True)])
+    def test_hessian_gathered_only_for_recipes_that_read_it(
+            self, tiny, monkeypatch, recipe, hessian):
+        seen = []
+        run = InstanceOptimizer.run_calibration
+
+        def spy(self, batch, *, hessian=True):
+            seen.append(hessian)
+            return run(self, batch, hessian=hessian)
+
+        monkeypatch.setattr(InstanceOptimizer, "run_calibration", spy)
+        make_session(tiny, recipes=[recipe])._optimize("qsig1", PROMPTS)
+        assert seen == [hessian]
 
     def test_acc_objective_picks_acc_variant(self, tiny):
         sess = make_session(tiny, objective="acc",

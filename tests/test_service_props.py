@@ -6,6 +6,7 @@ run unconditionally in tests/test_service.py; these push the same
 invariants through randomized schedules."""
 import statistics
 
+import numpy as np
 import pytest
 
 pytest.importorskip(
@@ -39,8 +40,29 @@ def test_reservoir_exact_below_capacity(data):
         statistics.quantiles(data, n=100, method="inclusive")[98])
 
 
-@given(data=st.lists(lat, min_size=1500, max_size=2500),
-       seed=st.integers(0, 2 ** 16))
+def _stream(n, seed, dist):
+    rng = np.random.default_rng(seed)
+    draw = {"uniform": lambda: rng.uniform(0.0, 1e6, n),
+            "exponential": lambda: rng.exponential(1e3, n),
+            "lognormal": lambda: rng.lognormal(3.0, 2.0, n),
+            # tie-heavy: few distinct values, zeros and the range's ends
+            "levels": lambda: np.round(rng.uniform(0.0, 1e6, n), -5),
+            "ties": lambda: rng.choice([0.0, 0.5, 1e3, 1e6], n)}[dist]
+    return [float(x) for x in draw()]
+
+
+# Streams past the reservoir's capacity (1500+ values) exceed what
+# hypothesis may draw value by value, so the stream is generated from
+# a drawn length, seed and latency distribution (continuous, or with
+# many repeated values).
+overflow_streams = st.builds(_stream, st.integers(1500, 2500),
+                             st.integers(0, 2 ** 32 - 1),
+                             st.sampled_from(["uniform", "exponential",
+                                              "lognormal", "levels",
+                                              "ties"]))
+
+
+@given(data=overflow_streams, seed=st.integers(0, 2 ** 16))
 @settings(max_examples=15, deadline=None)
 def test_reservoir_overflow_estimate_rank_tolerance(data, seed):
     """Beyond capacity the estimate is a sample quantile: assert rank
