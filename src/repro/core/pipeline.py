@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core import calibrate as C
 from repro.core import prune as P
 from repro.core import quantize as Q
@@ -188,7 +189,9 @@ class InstanceOptimizer:
 
     # -- stage 0: calibration ------------------------------------------------
     def run_calibration(self, batch: Dict[str, Any], *, hessian: bool = True):
-        self.stats = C.calibrate(self.params, self.cfg, batch, hessian=hessian)
+        with tracing.span("iolm.calibrate"):
+            self.stats = C.calibrate(self.params, self.cfg, batch,
+                                     hessian=hessian)
         return self.stats
 
     # -- full pipeline -------------------------------------------------------

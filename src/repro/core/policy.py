@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.compressed import param_bytes
 from repro.core.pipeline import InstanceOptimizer, Recipe
 
@@ -170,19 +171,25 @@ def search(optimizer: InstanceOptimizer, eval_fn: Callable,
            recipes: List[Recipe], *, acc_floor: float = 0.9,
            keep_params: bool = False) -> SearchOutcome:
     """Compress with every recipe, evaluate, select Perf/Acc variants."""
-    baseline = eval_fn(optimizer.params, optimizer.cfg)
-    cands: List[Candidate] = []
-    dropped: List[Tuple[str, str]] = []
-    for r in recipes:
-        try:
-            params2, cfg2, report = optimizer.apply(r)
-            res = eval_fn(params2, cfg2)
-        except Exception as e:  # a recipe inapplicable to this family
-            dropped.append((r.name, f"{type(e).__name__}: {e}"))
-            continue
-        cands.append(Candidate(recipe=r, result=res, report=report,
-                               params=params2 if keep_params else None,
-                               cfg=cfg2))
+    with tracing.span("iolm.search"):
+        with tracing.span("iolm.eval", recipe="baseline"):
+            baseline = eval_fn(optimizer.params, optimizer.cfg)
+        cands: List[Candidate] = []
+        dropped: List[Tuple[str, str]] = []
+        for r in recipes:
+            try:
+                with tracing.span("iolm.compress", recipe=r.name) as rec:
+                    params2, cfg2, report = optimizer.apply(r)
+                    if rec:     # keep its device work out of the eval
+                        jax.block_until_ready(params2)
+                with tracing.span("iolm.eval", recipe=r.name):
+                    res = eval_fn(params2, cfg2)
+            except Exception as e:  # a recipe inapplicable to this family
+                dropped.append((r.name, f"{type(e).__name__}: {e}"))
+                continue
+            cands.append(Candidate(recipe=r, result=res, report=report,
+                                   params=params2 if keep_params else None,
+                                   cfg=cfg2))
     perf = acc = None
     ok = [c for c in cands if c.result.accuracy >= acc_floor]
     pool = ok or cands

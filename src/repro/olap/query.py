@@ -31,6 +31,7 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import jax.numpy as jnp
 
+from repro import tracing
 from repro.core.calibrate import CascadeCalibration, fit_confidence_threshold
 from repro.core.compressed import kernel_backend
 from repro.core.pipeline import InstanceOptimizer, Recipe
@@ -255,52 +256,53 @@ class IOLMSession:
         if cached is not None:
             self.log.append(f"[iolm] model cache hit for {qsig}")
             return cached
-        self.recalibrations += 1
-        t0 = time.time()
-        sample = prompts[: self.calib_rows]
-        toks, _ = self.tok.pad_batch(
-            [self.tok.encode(p, bos=True) for p in sample],
-            seq_len=max(16, max(len(p) + 2 for p in sample)))
-        batch = {"tokens": jnp.asarray(toks)}
-        recipes = self.recipes or POL.default_recipe_space(self.cfg)
-        opt = InstanceOptimizer(self.params, self.cfg)
-        # the d_in x d_in Hessians are the costly statistic: gather them
-        # only for a recipe that reads them
-        opt.run_calibration(batch,
-                            hessian=any(r.needs_hessian for r in recipes))
-        hold = prompts[self.calib_rows:
-                       self.calib_rows + self.eval_rows] or sample
-        htoks, hlens = self.tok.pad_batch(
-            [self.tok.encode(p, bos=True) + [self.tok.SEP] for p in hold],
-            seq_len=max(16, max(len(p) + 3 for p in hold)))
-        # candidates are scored on the session's kernel backend
-        with kernel_backend(self.backend):
-            eval_fn = POL.make_agreement_eval(
-                self.params, self.cfg, jnp.asarray(htoks), max_new=12,
-                lengths=jnp.asarray(hlens))
-            outcome = POL.search(opt, eval_fn, recipes,
-                                 acc_floor=self.acc_floor, keep_params=True)
-        for name, err in outcome.dropped:
-            self.dropped_recipes.append((qsig, name, err))
-            self.log.append(f"[iolm] {qsig}: dropped {name}: {err}")
-        pick = outcome.perf if self.objective == "perf" else outcome.acc
-        if pick is None:  # nothing survived: identity model
-            m = OptimizedModel(self.params, self.cfg, None,
-                               Recipe(name="identity"), "base")
-        else:
-            # the version carries the DATA signature too: compression is
-            # calibration-dependent, so same-prompt queries over
-            # different data are different models — pool residency,
-            # result-cache and prefix-cache keys must never collapse
-            # them onto one tenant's params
-            m = OptimizedModel(pick.params, pick.cfg, pick.report,
-                               pick.recipe,
-                               f"{qsig}:{dsig}:{pick.recipe.name}")
-            self.log.append(
-                f"[iolm] {qsig}: picked {pick.recipe.name} "
-                f"acc={pick.result.accuracy:.2f} "
-                f"{pick.result.bytes / 1e6:.1f}MB "
-                f"({time.time() - t0:.1f}s to optimize)")
+        with tracing.span("iolm.optimize", qsig=qsig):
+            self.recalibrations += 1
+            t0 = time.time()
+            sample = prompts[: self.calib_rows]
+            toks, _ = self.tok.pad_batch(
+                [self.tok.encode(p, bos=True) for p in sample],
+                seq_len=max(16, max(len(p) + 2 for p in sample)))
+            batch = {"tokens": jnp.asarray(toks)}
+            recipes = self.recipes or POL.default_recipe_space(self.cfg)
+            opt = InstanceOptimizer(self.params, self.cfg)
+            # the d_in x d_in Hessians are the costly statistic: gather them
+            # only for a recipe that reads them
+            opt.run_calibration(batch,
+                                hessian=any(r.needs_hessian for r in recipes))
+            hold = prompts[self.calib_rows:
+                           self.calib_rows + self.eval_rows] or sample
+            htoks, hlens = self.tok.pad_batch(
+                [self.tok.encode(p, bos=True) + [self.tok.SEP] for p in hold],
+                seq_len=max(16, max(len(p) + 3 for p in hold)))
+            # candidates are scored on the session's kernel backend
+            with kernel_backend(self.backend):
+                eval_fn = POL.make_agreement_eval(
+                    self.params, self.cfg, jnp.asarray(htoks), max_new=12,
+                    lengths=jnp.asarray(hlens))
+                outcome = POL.search(opt, eval_fn, recipes,
+                                     acc_floor=self.acc_floor, keep_params=True)
+            for name, err in outcome.dropped:
+                self.dropped_recipes.append((qsig, name, err))
+                self.log.append(f"[iolm] {qsig}: dropped {name}: {err}")
+            pick = outcome.perf if self.objective == "perf" else outcome.acc
+            if pick is None:  # nothing survived: identity model
+                m = OptimizedModel(self.params, self.cfg, None,
+                                   Recipe(name="identity"), "base")
+            else:
+                # the version carries the DATA signature too: compression is
+                # calibration-dependent, so same-prompt queries over
+                # different data are different models — pool residency,
+                # result-cache and prefix-cache keys must never collapse
+                # them onto one tenant's params
+                m = OptimizedModel(pick.params, pick.cfg, pick.report,
+                                   pick.recipe,
+                                   f"{qsig}:{dsig}:{pick.recipe.name}")
+                self.log.append(
+                    f"[iolm] {qsig}: picked {pick.recipe.name} "
+                    f"acc={pick.result.accuracy:.2f} "
+                    f"{pick.result.bytes / 1e6:.1f}MB "
+                    f"({time.time() - t0:.1f}s to optimize)")
         self.model_cache.put(qsig, dsig, m)
         return m
 

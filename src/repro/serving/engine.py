@@ -77,6 +77,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.core.compressed import kernel_backend
 from repro.kernels.backend import resolve_backend
 from repro.launch.mesh import auto_axes
@@ -106,6 +107,7 @@ class EngineStats:
     prefix_hits: int = 0         # rows seeded from a shared prefix state
     prefill_tokens: int = 0      # padded prompt tokens actually prefilled
     prefill_tokens_saved: int = 0  # prefix tokens NOT re-prefilled per row
+    host_syncs: int = 0          # device->host pulls (2 per admission, 2 per decode)
     backend: str = ""            # resolved KernelBackend ("reference"/"pallas")
     kv_blocks_in_use: int = 0    # peak KV blocks reachable (paged layout)
     kv_blocks_shared: int = 0    # peak blocks aliased by >1 slot (paged)
@@ -545,17 +547,55 @@ class Engine:
             self._init_slots()
         finished: List[Request] = []
         free = [s for s in range(self.slots) if s not in self._active]
-        # --- admit: one bucketed prefill + ONE batched slot insert ---
         if free and len(self.batcher):
             take = self.batcher.take(len(free))
             if take:
-                top = self.buckets[-1]
-                for r in take:
-                    if len(r.prompt_ids) > top:
-                        r.truncated = True
-                        self.stats.truncated += 1
-                b = bucket_len(max(len(r.prompt_ids) for r in take),
-                               self.buckets)
+                finished = self._admit(take, free)
+        if not self._active:
+            return StepPending(finished, None)
+        # --- decode one token for every active slot (launch only) ---
+        with tracing.span("engine.decode") as rec:
+            if rec:
+                rec.attrs["kv_lens"] = [int(self._cur_pos[s]) + 1
+                                        for s in self._active]
+            if self._paged:
+                used, sh = self._alloc.stats()
+                self.stats.kv_blocks_in_use = max(
+                    self.stats.kv_blocks_in_use, used)
+                self.stats.kv_blocks_shared = max(
+                    self.stats.kv_blocks_shared, sh)
+                nxt, conf, self._slot_state = self._decode(
+                    self.params, self._slot_state, self._tables(),
+                    jnp.asarray(self._cur_tok), jnp.asarray(self._cur_pos),
+                    jnp.int32(self._decode_ctr))
+            else:
+                nxt, conf, self._slot_state = self._decode(
+                    self.params, self._slot_state,
+                    jnp.asarray(self._cur_tok), jnp.asarray(self._cur_pos),
+                    jnp.int32(self._decode_ctr))
+        self._decode_ctr += 1
+        self.stats.decode_steps += 1
+        self.stats.busy_slot_steps += len(self._active)
+        self.stats.total_slot_steps += self.slots
+        return StepPending(finished, (nxt, conf))
+
+    def _admit(self, take: List[Request], free: List[int]) -> List[Request]:
+        """One bucketed prefill + ONE batched slot insert for ``take``
+        into the first free slots; returns the rows that finished at
+        admission."""
+        finished: List[Request] = []
+        with tracing.span("engine.admit") as admit:
+            tok0 = self.stats.prefill_tokens
+            top = self.buckets[-1]
+            for r in take:
+                if len(r.prompt_ids) > top:
+                    r.truncated = True
+                    self.stats.truncated += 1
+            b = bucket_len(max(len(r.prompt_ids) for r in take),
+                           self.buckets)
+            with tracing.span("engine.prefill") as rec:
+                if rec:
+                    rec.attrs.update(bucket=b, rows=len(take))
                 toks = np.zeros((len(take), b), np.int32)
                 for i, r in enumerate(take):
                     ids = r.prompt_ids[-b:]
@@ -588,6 +628,7 @@ class Engine:
                         jnp.asarray(lens, jnp.int32))
                 self.stats.prefills += 1
                 self.stats.prefill_tokens += len(take) * b
+            with tracing.span("engine.first_token"):
                 # rows are right-padded: gather each row's logits at its
                 # last REAL position, not at the padding tail
                 last_logits = jnp.take_along_axis(
@@ -609,6 +650,8 @@ class Engine:
                 # the decode loop), so its confidence is computed here too
                 first_conf = np.asarray(
                     token_confidence(last_logits, first_dev), np.float64)
+                self.stats.host_syncs += 2
+            with tracing.span("engine.insert"):
                 slot_idxs = np.asarray(free[:len(take)], np.int32)
                 if self._paged:
                     w_ids = self._paged_admit_ids(slot_idxs, pk, plen, entry)
@@ -618,42 +661,28 @@ class Engine:
                 else:
                     self._slot_state = self._insert(
                         self._slot_state, rows, jnp.asarray(slot_idxs))
-                for i, r in enumerate(take):
-                    s = int(slot_idxs[i])
-                    t0 = int(first[i])
-                    r.out_ids.append(t0)
-                    r.confidence = min(r.confidence, float(first_conf[i]))
-                    if t0 == self.tok.EOS or len(r.out_ids) >= r.max_new:
-                        # prefill token already ends the row (EOS) or
-                        # exhausts the budget: retire without ever
-                        # occupying a decode slot
-                        self._release_slot(s)
-                        finished.extend(self._retire(r))
-                        continue
-                    self._active[s] = r
-                    self._cur_tok[s] = t0
-                    self._cur_pos[s] = plen + int(lens[i])
-        if not self._active:
-            return StepPending(finished, None)
-        # --- decode one token for every active slot (launch only) ---
-        if self._paged:
-            used, sh = self._alloc.stats()
-            self.stats.kv_blocks_in_use = max(self.stats.kv_blocks_in_use,
-                                              used)
-            self.stats.kv_blocks_shared = max(self.stats.kv_blocks_shared, sh)
-            nxt, conf, self._slot_state = self._decode(
-                self.params, self._slot_state, self._tables(),
-                jnp.asarray(self._cur_tok), jnp.asarray(self._cur_pos),
-                jnp.int32(self._decode_ctr))
-        else:
-            nxt, conf, self._slot_state = self._decode(
-                self.params, self._slot_state, jnp.asarray(self._cur_tok),
-                jnp.asarray(self._cur_pos), jnp.int32(self._decode_ctr))
-        self._decode_ctr += 1
-        self.stats.decode_steps += 1
-        self.stats.busy_slot_steps += len(self._active)
-        self.stats.total_slot_steps += self.slots
-        return StepPending(finished, (nxt, conf))
+            for i, r in enumerate(take):
+                s = int(slot_idxs[i])
+                t0 = int(first[i])
+                r.out_ids.append(t0)
+                r.confidence = min(r.confidence, float(first_conf[i]))
+                if t0 == self.tok.EOS or len(r.out_ids) >= r.max_new:
+                    # prefill token already ends the row (EOS) or
+                    # exhausts the budget: retire without ever
+                    # occupying a decode slot
+                    self._release_slot(s)
+                    finished.extend(self._retire(r))
+                    continue
+                self._active[s] = r
+                self._cur_tok[s] = t0
+                self._cur_pos[s] = plen + int(lens[i])
+            if admit:
+                admit.attrs.update(
+                    rids=[r.rid for r in take], bucket=b,
+                    suffix_lens=[len(r.prompt_ids) for r in take],
+                    prefix_lens=[len(r.prefix_ids or ()) for r in take],
+                    tokens=self.stats.prefill_tokens - tok0)
+        return finished
 
     def step_finish(self, pending: StepPending) -> List[Request]:
         """Second half of a tick: block on the launched decode, then
@@ -663,21 +692,27 @@ class Engine:
         if nxt is None:
             return finished
         nxt, conf = nxt
-        nxt = np.asarray(nxt)
-        conf = np.asarray(conf)
+        with tracing.span("engine.pull"):
+            nxt = np.asarray(nxt)
+            conf = np.asarray(conf)
+        self.stats.host_syncs += 2
         # --- retire / advance ---
-        for s in list(self._active):
-            r = self._active[s]
-            t = int(nxt[s])
-            r.out_ids.append(t)
-            r.confidence = min(r.confidence, float(conf[s]))
-            self._cur_tok[s] = t
-            self._cur_pos[s] += 1
-            if t == self.tok.EOS or len(r.out_ids) >= r.max_new \
-                    or self._cur_pos[s] >= self.max_len - 1:
-                del self._active[s]
-                self._release_slot(s)
-                finished.extend(self._retire(r))
+        with tracing.span("engine.retire") as rec:
+            n0 = len(finished)
+            for s in list(self._active):
+                r = self._active[s]
+                t = int(nxt[s])
+                r.out_ids.append(t)
+                r.confidence = min(r.confidence, float(conf[s]))
+                self._cur_tok[s] = t
+                self._cur_pos[s] += 1
+                if t == self.tok.EOS or len(r.out_ids) >= r.max_new \
+                        or self._cur_pos[s] >= self.max_len - 1:
+                    del self._active[s]
+                    self._release_slot(s)
+                    finished.extend(self._retire(r))
+            if rec:
+                rec.attrs["rows"] = len(finished) - n0
         return finished
 
     def has_work(self) -> bool:

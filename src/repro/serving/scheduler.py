@@ -72,6 +72,7 @@ from typing import (Any, Callable, Dict, Iterable, Iterator, List, Optional,
 import jax
 import numpy as np
 
+from repro import tracing
 from repro.core.compressed import param_bytes
 from repro.models import api
 from repro.serving.batcher import Request
@@ -530,7 +531,6 @@ class Scheduler:
         self.active: List[Submission] = []
         self.finished: List[Submission] = []
         self.stats = SchedulerStats()
-        self.trace: List[Tuple[int, str]] = []   # (tick, tenant) per row
         self._owners: Dict[Tuple[int, int], Submission] = {}
         self._t0: Dict[Tuple[int, int], float] = {}   # row submit times
         self._rr = 0
@@ -620,7 +620,6 @@ class Scheduler:
 
     def _record_done(self, sub: Submission, latency: float = 0.0) -> None:
         self.stats.rows += 1
-        self.trace.append((self.stats.ticks, sub.tenant))
         ts = self.stats.tenant(sub.tenant)
         ts.rows += 1
         ts.latency.add(latency)
@@ -706,14 +705,25 @@ class Scheduler:
 
     def step(self) -> bool:
         """One fair-share tick; returns True while work remains."""
+        with tracing.span("engine.schedule") as rec:
+            more = self._tick()
+            if rec:
+                rec.attrs["tick"] = self.stats.ticks
+            return more
+
+    def _tick(self) -> bool:
         self._activate()
         self.stats.ticks += 1
         order = list(self.active)   # snapshot: quarantine may mutate
         n = len(order)
-        for i in range(n):          # rotating round-robin admission
-            sub = order[(self._rr + i) % n]
-            if sub.engine is not None:   # skip mid-tick quarantined
-                self._top_up(sub)
+        with tracing.span("engine.top_up") as rec:
+            rows0 = sum(len(sub.reqs) for sub in order) if rec else 0
+            for i in range(n):          # rotating round-robin admission
+                sub = order[(self._rr + i) % n]
+                if sub.engine is not None:   # skip mid-tick quarantined
+                    self._top_up(sub)
+            if rec:
+                rec.attrs["rows"] = sum(len(sub.reqs) for sub in order) - rows0
         if n:
             self._rr = (self._rr + 1) % n
         # one decode tick per distinct engine with work, in activation
