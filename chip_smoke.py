@@ -280,7 +280,10 @@ def kernel_audit():
             return y
         return call
 
-    def quant_ref(x, q, scale, *, group, in_scale=None, **_):
+    def quant_ref(x, q, scale, *, group, in_scale=None, layer=None, **_):
+        if layer is not None:       # one layer of a stacked weight
+            q, scale = q[layer], scale[layer]
+            in_scale = None if in_scale is None else in_scale[layer]
         return _q_matmul_jnp(x, QTensor(q, scale, 8, group, q.shape,
                                         in_scale))
 

@@ -191,6 +191,21 @@ class QTensor:
         return w.astype(jnp.bfloat16)
 
 
+class QLayer:
+    """Layer ``layer`` of an int8 ``QTensor`` stacked for ``lax.scan``,
+    left in the stack: the Pallas kernel reads that layer's tiles in
+    place, where slicing the stack first copies the layer's codes.
+    Other backends take ``sliced()``.  Not a pytree: it lives inside
+    one traced step."""
+
+    def __init__(self, stacked: QTensor, layer):
+        self.stacked = stacked
+        self.layer = layer
+
+    def sliced(self) -> QTensor:
+        return jax.tree_util.tree_map(lambda c: c[self.layer], self.stacked)
+
+
 def pack_int4(codes: jax.Array) -> jax.Array:
     """int8 codes in [-8, 7], even first dim -> packed uint8 pairs."""
     lo = codes[0::2].astype(jnp.uint8) & 0xF
@@ -328,6 +343,13 @@ def _q_matmul_jnp(x: jax.Array, w: QTensor) -> jax.Array:
 
 def matmul(x: jax.Array, w) -> jax.Array:
     """Universal ``x @ w`` over raw / quantized / block-sparse weights."""
+    if isinstance(w, QLayer):
+        if current_backend() == "pallas":
+            from repro.kernels import ops as kops
+            s = w.stacked
+            return kops.quant_matmul(x, s.q, s.scale, group=s.group,
+                                     in_scale=s.in_scale, layer=w.layer)
+        w = w.sliced()
     if isinstance(w, QTensor):
         if w.bits == 8 and current_backend() == "pallas":
             from repro.kernels import ops as kops

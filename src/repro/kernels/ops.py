@@ -9,13 +9,17 @@ the active KernelBackend resolves to ``"pallas"``.
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.custom_batching import custom_vmap
 
+from repro import tracing
 from repro.kernels.block_sparse import block_sparse_matmul_kernel
 from repro.kernels.flash_attention import flash_attention_kernel
 from repro.kernels.paged_attention import paged_attention_kernel
-from repro.kernels.quant_matmul import quant_matmul_kernel
+from repro.kernels.quant_matmul import quant_matmul_kernel, tiles
 
 
 def _off_tpu() -> bool:
@@ -37,7 +41,7 @@ def _pad_rows(x2, bm):
     return x2, M
 
 
-def quant_matmul(x, q, scale, *, group: int, in_scale=None,
+def quant_matmul(x, q, scale, *, group: int, in_scale=None, layer=None,
                  interpret=None):
     """x [..., K] @ dequant(q, scale) with int8 codes kept in HBM.
 
@@ -48,28 +52,70 @@ def quant_matmul(x, q, scale, *, group: int, in_scale=None,
     be BYTE-identical to ``"reference"`` on CPU (the serving identity
     gate in tests/test_paged_cache.py and ``benchmarks/roofline.py
     --smoke``).  Pass ``interpret=True`` explicitly to run the real
-    kernel under the Pallas interpreter (tests/test_kernels.py)."""
-    K, N = q.shape
+    kernel under the Pallas interpreter (tests/test_kernels.py).
+
+    Under ``jax.vmap`` of ``x`` alone, the batch is folded into rows:
+    one kernel call over every row that shares the weight, so each
+    weight tile is fetched once per row tile of the whole batch.  A
+    batched ``q`` or ``scale`` keeps the kernel's batched grid.  Each
+    call records one ``kernel.quant_matmul`` span at trace time, whose
+    attributes describe the call as it is lowered (``rows``, ``K``,
+    ``N``, the tiles, ``folded``, and ``weight_passes``: how many times
+    the call fetches each weight tile).
+
+    With ``layer``, ``q``, ``scale`` and ``in_scale`` are stacks over a
+    leading layer axis, and the kernel reads that layer in place."""
+    if layer is not None and in_scale is not None:
+        in_scale = in_scale[layer]
     if interpret is None:
         if _off_tpu():
             from repro.core.compressed import QTensor, _q_matmul_jnp
-            return _q_matmul_jnp(x, QTensor(q, scale, 8, group, (K, N),
+            if layer is not None:
+                q, scale = q[layer], scale[layer]
+            return _q_matmul_jnp(x, QTensor(q, scale, 8, group, q.shape,
                                             in_scale))
         interpret = False
     if in_scale is not None:
         x = (x.astype(jnp.float32) * in_scale).astype(x.dtype)
-    x2 = x.reshape(-1, K)
-    bm = 128 if x2.shape[0] >= 128 else 8
-    x2, M = _pad_rows(x2, bm)
-    # K tile: whole groups whose [bk // group, bn] scale block has a
-    # multiple of 8 rows, else all of K — the TPU lowering refuses any
-    # other second-minor block size of the [K // group, N] scale array
-    tile = 8 * group * max(1, 512 // (8 * group))
-    bk = tile if K % tile == 0 else K
-    y = quant_matmul_kernel(x2, q, scale, group=group, bm=bm, bk=bk,
-                            bn=128 if N % 128 == 0 else N,
-                            interpret=interpret)
-    return y[:M].reshape(*x.shape[:-1], N)
+    layer = jnp.zeros((), jnp.int32) if layer is None else layer
+    with tracing.span("kernel.quant_matmul") as rec:
+        return _foldable_quant_matmul(group, interpret, rec)(x, q, scale,
+                                                             layer)
+
+
+def _foldable_quant_matmul(group, interpret, rec):
+    """The kernel call as a ``custom_vmap`` function whose batching rule
+    folds a batched ``x`` over a shared weight and layer into rows.
+    ``rec`` (the call's span record, or None) takes the attributes of
+    the last trace, which is the one lowered: a batching rule traces
+    after the unbatched body it replaces."""
+    def call(x, q, scale, layer, *, folded=False, batch=1):
+        K, N = q.shape[-2:]
+        x2 = x.reshape(-1, K)
+        M = x2.shape[0]
+        bm, bn, bk = tiles(M, K, N, group, x.dtype.itemsize)
+        row_tiles = -(-M // bm)
+        if rec is not None:
+            rec.attrs.update(rows=M, K=K, N=N, bm=bm, bn=bn, bk=bk,
+                             folded=folded,
+                             weight_passes=row_tiles * batch)
+        x2, _ = _pad_rows(x2, bm)
+        y = quant_matmul_kernel(x2, q, scale, group=group, bm=bm, bn=bn,
+                                bk=bk, layer=layer, interpret=interpret)
+        return y[:M].reshape(*x.shape[:-1], N)
+
+    def rule(axis_size, in_batched, x, q, scale, layer):
+        if any(in_batched[1:]):
+            axes = [0 if b else None for b in in_batched]
+            return jax.vmap(functools.partial(call, batch=axis_size),
+                            in_axes=axes)(x, q, scale, layer), True
+        return folded(x, q, scale, layer), True
+
+    plain = custom_vmap(call)
+    folded = custom_vmap(functools.partial(call, folded=True))
+    plain.def_vmap(rule)
+    folded.def_vmap(rule)
+    return plain
 
 
 def block_sparse_matmul(x, w, idx, *, bs: int, interpret=None):

@@ -7,10 +7,11 @@ roofline term that dominates decode; each grid step copies one
 feeds the MXU.  Accumulation is f32 in a VMEM scratch tile across the K
 grid dimension.
 
-Tile choice (v5e): bm=*rows*, bn=128 (lane width), bk=512.  The working
-set per step is  x[bm,bk] bf16 + q[bk,bn] int8 + scale[bk/g,bn] f32 +
-acc[bm,bn] f32  ≈ 128·512·2 + 512·128·1 + 4·128·4 + 128·128·4 ≈ 0.26 MB
-— comfortably inside the ~16 MB VMEM with double buffering.
+Tiles come from the whole call (``tiles``): every weight tile is
+fetched and dequantized once per row tile, so one row tile covers up
+to ``ROW_CAP`` rows, and column tiles are as wide as ``BN_CAP`` and the
+VMEM budget allow.  ``bk`` takes whole quant groups and leaves each
+output element's f32 summation order over K as it was.
 """
 from __future__ import annotations
 
@@ -21,9 +22,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+ROW_CAP = 512                  # most rows in one row tile
+BN_CAP = 2048                  # widest column tile
+VMEM_BUDGET = 32 * 2 ** 20     # the kernel's VMEM limit, and its tiles' budget
 
-def _kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, nk: int, group: int,
-            out_dtype):
+
+def _kernel(layer_ref, x_ref, q_ref, s_ref, o_ref, acc_ref, *, nk: int,
+            group: int, out_dtype):
+    del layer_ref                       # read by the index maps
     k = pl.program_id(2)
 
     @pl.when(k == 0)
@@ -44,33 +50,94 @@ def _kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, nk: int, group: int,
         o_ref[...] = acc_ref[...].astype(out_dtype)
 
 
-def quant_matmul_kernel(x, q, scale, *, group: int, bm: int = 128,
-                        bn: int = 128, bk: int = 512,
-                        interpret: bool = False):
-    """x [M, K] bf16 @ dequant(q [K, N] int8, scale [K/g, N] f32) -> [M, N].
+def k_tile(K: int, group: int) -> int:
+    """Whole groups whose ``[bk // group, bn]`` scale block has a multiple
+    of 8 rows, else all of K: the TPU lowering refuses any other
+    second-minor block size of the ``[K // group, N]`` scale array."""
+    tile = 8 * group * max(1, 512 // (8 * group))
+    return tile if K % tile == 0 else K
 
-    Shapes must tile exactly (the ops.py wrapper pads).
-    """
+
+def row_tile(M: int, cap: int = ROW_CAP) -> int:
+    """One tile of all M rows up to ``cap`` (a block as tall as the array
+    needs no padding); above it, equal tiles rounded up to 8 rows, so
+    padding adds fewer than 8 rows a tile."""
+    if M <= cap:
+        return M
+    per = -(-M // -(-M // cap))
+    return -(-per // 8) * 8
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, group: int, itemsize: int) -> int:
+    """The kernel's VMEM working set, from above: double-buffered x, codes,
+    scales and output, the f32 accumulator and one f32 product, and the
+    bf16 dequantized tile, each padded to whole (8, 128) tiles (int8
+    codes to (32, 128))."""
+    def tile(rows, cols, nbytes, sub=8):
+        return -(-rows // sub) * sub * (-(-cols // 128) * 128) * nbytes
+    buffers = (tile(bm, bk, itemsize) + tile(bk, bn, 1, 32)
+               + tile(bk // group, bn, 4) + tile(bm, bn, itemsize))
+    return 2 * buffers + 2 * tile(bm, bn, 4) + tile(bk, bn, 2)
+
+
+def tiles(M: int, K: int, N: int, group: int, itemsize: int):
+    """``(bm, bn, bk)`` for x [M, K] (``itemsize`` bytes an element) times
+    [K, N] codes: the tallest row tile, then the widest ``bn`` whose
+    working set fits ``VMEM_BUDGET``: a multiple of 128 dividing N, or,
+    where 128 does not divide N, all of N, else a multiple of 128 whose
+    last column block runs past N (its extra columns are dropped)."""
+    bk = k_tile(K, group)
+    lanes = range(min(N, BN_CAP) // 128 * 128, 0, -128)
+    if N % 128:
+        widths = [N, *lanes]
+    else:
+        widths = [b for b in lanes if N % b == 0]
+    cap = ROW_CAP
+    while True:
+        bm = row_tile(M, cap)
+        for bn in widths:
+            if vmem_bytes(bm, bn, bk, group, itemsize) <= VMEM_BUDGET:
+                return bm, bn, bk
+        if cap == 8:
+            raise ValueError(f"no quant_matmul tile fits VMEM for "
+                             f"M={M} K={K} N={N} group={group}")
+        cap //= 2
+
+
+def quant_matmul_kernel(x, q, scale, *, group: int, bm: int, bn: int,
+                        bk: int, layer=0, interpret: bool = False):
+    """x [M, K] @ dequant(q [K, N] int8, scale [K/g, N] f32) -> [M, N].
+
+    ``q`` and ``scale`` may be stacks ``[L, K, N]`` and ``[L, K/g, N]``:
+    the kernel then reads layer ``layer`` (a scalar, prefetched) in
+    place.  Rows and K must tile exactly (the ops.py wrapper pads rows);
+    a last column block may run past N."""
+    if q.ndim == 2:
+        q, scale = q[None], scale[None]
     M, K = x.shape
-    K2, N = q.shape
-    assert K == K2 and scale.shape == (K // group, N), (x.shape, q.shape,
-                                                        scale.shape)
-    bm, bn, bk = min(bm, M), min(bn, N), min(bk, K)
-    assert M % bm == 0 and N % bn == 0 and K % bk == 0, (M, N, K, bm, bn, bk)
+    _, K2, N = q.shape
+    assert K == K2 and scale.shape[1:] == (K // group, N), (
+        x.shape, q.shape, scale.shape)
+    assert M % bm == 0 and K % bk == 0, (M, K, bm, bk)
     assert bk % group == 0, (bk, group)
     nk = K // bk
-    grid = (M // bm, N // bn, nk)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(M // bm, pl.cdiv(N, bn), nk),
+        in_specs=[
+            pl.BlockSpec((bm, bk), lambda i, j, k, l: (i, k)),
+            pl.BlockSpec((None, bk, bn), lambda i, j, k, l: (l[0], k, j)),
+            pl.BlockSpec((None, bk // group, bn),
+                         lambda i, j, k, l: (l[0], k, j)),
+        ],
+        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k, l: (i, j)),
+        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+    )
     return pl.pallas_call(
         functools.partial(_kernel, nk=nk, group=group, out_dtype=x.dtype),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
-            pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
-            pl.BlockSpec((bk // group, bn), lambda i, j, k: (k, j)),
-        ],
-        out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=VMEM_BUDGET),
         name="quant_matmul",
         interpret=interpret,
-    )(x, q, scale)
+    )(jnp.reshape(jnp.asarray(layer, jnp.int32), (1,)), x, q, scale)

@@ -16,6 +16,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.compressed import QLayer, QTensor
 from repro.distributed.sharding import constrain
 from repro.models import layers as L
 from repro.models.layers import matmul, norm
@@ -501,6 +502,19 @@ def paged_block_decode(p, c, x, cfg, *, kind: str, pos, tables,
     return x + _mlp_section(p, h, cfg), c2
 
 
+def _layer_of(stacked, i):
+    """Layer ``i`` of scan-stacked params.  An int8 weight matrix stays in
+    its stack as a ``QLayer`` (the decode step reads every weight once
+    per token, and a slice would first copy it); every other leaf is
+    indexed."""
+    def one(a):
+        if isinstance(a, QTensor) and a.bits == 8 and a.q.ndim == 3:
+            return QLayer(a, i)
+        return jax.tree_util.tree_map(lambda c: c[i], a)
+    return jax.tree_util.tree_map(
+        one, stacked, is_leaf=lambda a: isinstance(a, QTensor))
+
+
 def paged_decode_step(params: Params, cfg, cache, tables, tokens, pos, *,
                       block_size: int, max_len: int,
                       backend: str = "reference"):
@@ -513,7 +527,8 @@ def paged_decode_step(params: Params, cfg, cache, tables, tokens, pos, *,
     unit, R, tail = pattern_unit(cfg)
 
     def body(xc, xs):
-        member_params, member_cache = xs
+        i, member_cache = xs
+        member_params = _layer_of(params["blocks"], i)
         new_caches = []
         for u, kind in enumerate(unit):
             xc, c2 = paged_block_decode(
@@ -524,7 +539,7 @@ def paged_decode_step(params: Params, cfg, cache, tables, tokens, pos, *,
         return xc, new_caches
 
     x, new_block_cache = jax.lax.scan(body, x,
-                                      (params["blocks"], cache["blocks"]),
+                                      (jnp.arange(R), cache["blocks"]),
                                       unroll=cfg.scan_unroll)
     new_tail = []
     for i, p in enumerate(params["tail"]):

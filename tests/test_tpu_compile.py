@@ -5,8 +5,10 @@ compiles each kernel at real model widths for a chip that is described,
 not attached.  That catches what interpret mode cannot — block shapes
 the TPU lowering refuses, unaligned slices, VMEM overuse — at no chip
 time.  Widths: gemma3-1b decode (4 q / 1 kv head of 256, d_model 1152,
-d_ff 6912, 512 window), and gemma2-2b's 4 kv heads for the paged
-kernel's multi-head block.
+d_ff 6912, 512 window), gemma2-2b's 4 kv heads for the paged
+kernel's multi-head block, and mistral-nemo-12b's int8 projections
+(d_model 5120, d_ff 14336, vocab 131072) in decode and in the vmapped
+prefill.
 
 The topology is described inside a module fixture (never at import):
 only one process at a time may load the TPU library, and every test
@@ -76,6 +78,59 @@ def test_quant_matmul_compiles(chip, k, n):
                                               interpret=False),
              chip, ((SLOTS, k), jnp.bfloat16), ((k, n), jnp.int8),
              ((k // group, n), jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", [(5120, 14336), (14336, 5120),
+                                 (5120, 131072)])
+def test_quant_matmul_nemo_decode_compiles(chip, k, n):
+    """mistral-nemo-12b's decode step: 16 slots in one row tile."""
+    group = 128
+    text = _compile(lambda x, q, s: ops.quant_matmul(x, q, s, group=group,
+                                                     interpret=False),
+                    chip, ((16, k), jnp.bfloat16), ((k, n), jnp.int8),
+                    ((k // group, n), jnp.float32))
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+
+
+def test_quant_matmul_nemo_prefill_folds_under_scan(chip):
+    """The engine's prefill: ``jax.vmap`` over 16 rows of 128 tokens of a
+    ``lax.scan`` over stacked layers.  The batch folds into one kernel
+    call over 2048 rows per layer, not a batched grid."""
+    group, k, n, layers = 128, 5120, 14336, 2
+
+    def prefill(x, q, s):
+        def layer(h, w):
+            y = ops.quant_matmul(h, w[0], w[1], group=group, interpret=False)
+            return h + y[..., :k], None
+        return jax.vmap(lambda xr: jax.lax.scan(layer, xr, (q, s))[0])(x)
+
+    text = _compile(prefill, chip, ((16, 128, k), jnp.bfloat16),
+                    ((layers, k, n), jnp.int8),
+                    ((layers, k // group, n), jnp.float32))
+    calls = [l for l in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in l]
+    assert len(calls) == 1 and f"bf16[2048,{n}]" in calls[0], calls
+
+
+def test_quant_matmul_reads_stacked_layers_in_place(chip):
+    """The paged decode step's layer loop hands the kernel the whole
+    ``[L, K, N]`` stack and the layer index: no per-layer copy of the
+    codes before each call."""
+    group, k, n, layers = 128, 5120, 14336, 3
+
+    def decode(x, q, s):
+        def layer(h, i):
+            y = ops.quant_matmul(h, q, s, group=group, layer=i,
+                                 interpret=False)
+            return h + y[..., :k], None
+        return jax.lax.scan(layer, x, jnp.arange(layers))[0]
+
+    text = _compile(decode, chip, ((16, k), jnp.bfloat16),
+                    ((layers, k, n), jnp.int8),
+                    ((layers, k // group, n), jnp.float32))
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert not [l for l in text.splitlines()
+                if "dynamic-slice" in l and "s8[" in l.split("=")[1][:12]]
 
 
 def test_block_sparse_matmul_compiles(chip):
