@@ -136,7 +136,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
     st = serve.build(cell, seed, watch=watch, sizes=sizes)
     setup_s = time.perf_counter() - t_start
     eng, probe = st.engine, st.probe
-    instance = flops.instance_matmuls(eng.params)
+    instance = flops.instance_matmuls(eng.params,
+                                      top_k=st.kw.get("top_k", 0))
     log("setup", setup_s=setup_s, parts=dict(st.timers.s),
         compile_s=watch.seconds, programs=watch.programs(),
         picked=st.picked, dropped=st.info["dropped_recipes"],
@@ -244,8 +245,12 @@ def _profile_options():
 def correctness(cell, st, win, seed: int, picked: str, *,
                 control: bool) -> Dict[str, Dict[str, float]]:
     """The served tokens of a sample of the window's rows against the
-    plain reference (``check.compare``)."""
+    plain reference (``check.compare``).  A configuration that names no
+    reference reads nothing, and its run is not correct."""
     from iolmbench import check
+    if "reference" not in cell.config:
+        log("no reference reading: the configuration names no reference")
+        return {"served_logit_gap": {"value": None, "limit": None}}
     lim = limits_for(cell.name)
     recipes = {r["name"]: r for r in cell.mix["session"]["recipes"]}
     recipes["base"] = {"name": "base"}          # the bf16 model as given
