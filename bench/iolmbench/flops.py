@@ -27,10 +27,26 @@ Expert stacks (``wi``/``wg``/``wo`` under ``.moe.``, ``[.., E, K, N]``)
 are left out of ``matmul_calls``: however the program dispatches them
 today, they are the expert layer's work, not a plain matmul's.
 ``expert_calls`` lists them, with ``expert_ops`` and ``expert_bytes``
-as their least operations and bytes.  A PR that changes the MoE
-dispatch brings its expert kernel's own ``bench/kernels/<kernel>.py``
-(whose ``ops`` and ``bytes_moved`` are these two) and its roofline
-reader under ``bench/metrics/``, which call ``expert_calls``.
+as their least operations and bytes.  An expert kernel comes as new
+files alone: ``bench/kernels/<kernel>.py``, whose ``ops`` and
+``bytes_moved`` are these two and which declares ``TAKES =
+"experts"`` (the trace reduction attributes every kernel that has such
+a file, and the presence check then expects this one for the expert
+stacks), and ``bench/metrics/<kernel>_roofline.py``, which calls
+``roofline.expert_share``.
+
+How many experts a call reached comes from the program, where its
+engine keeps two counters in ``EngineStats`` (the harness takes every
+integer counter there as its difference over the window):
+
+* ``moe_prefill_experts``: summed over the engine's admissions (each
+  bucketed prefill of admitted rows; the one-time prefill of a shared
+  prefix is not one) and over the MoE layers, the number of experts
+  that got at least one of the admission's token rows;
+* ``moe_decode_experts``: the same, summed over decode steps and MoE
+  layers, counting every row the step routes.
+
+Without them a call is taken to reach ``top_k`` experts, the fewest.
 """
 from __future__ import annotations
 

@@ -15,6 +15,7 @@ profiler trace of the whole window.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import re
@@ -27,7 +28,6 @@ from typing import Any, Dict, List, Optional
 
 from iolmbench import spec
 
-KERNELS = ("quant_matmul", "block_sparse_matmul", "paged_attention")
 # weight bits one step below each served precision (the control)
 LOWER_BITS = {16: 8, 8: 4}
 
@@ -95,8 +95,14 @@ def decode_kernels(engine) -> set:
     return set(re.findall(r'kernel_name = "(\w+)"', low.as_text()))
 
 
-def expected_kernels(instance) -> set:
-    return {"paged_attention"} | {w["kernel"] for w in instance}
+def expected_kernels(instance, bench_dir: str = spec.BENCH_DIR) -> set:
+    """Pallas kernels the served decode step has to hold: attention,
+    each projection's kernel, and for expert stacks the kernel whose
+    counts file takes them (``spec.expert_kernel``), where one does."""
+    experts = spec.expert_kernel(bench_dir)
+    return {"paged_attention"} | {
+        experts if experts and "experts" in w else w["kernel"]
+        for w in instance}
 
 
 def limits_for(cell_name: str) -> Dict[str, Any]:
@@ -104,12 +110,18 @@ def limits_for(cell_name: str) -> Dict[str, Any]:
                                        cell_name + ".json"))
 
 
+# EngineStats fields that hold a high-water mark, not a count: their
+# difference over the window means nothing
+HIGH_WATER = ("peak_inflight", "kv_blocks_in_use", "kv_blocks_shared")
+
+
 def _stats(eng) -> Dict[str, int]:
+    """Every counter of the engine's ``EngineStats``: its integer fields
+    but the high-water marks, so that a counter the program adds
+    reaches the readers (``Context.engine_stats``) as it is."""
     s = eng.stats
-    return {k: int(getattr(s, k)) for k in (
-        "rows", "tokens_out", "prefills", "decode_steps", "truncated",
-        "busy_slot_steps", "total_slot_steps", "prefix_hits",
-        "prefill_tokens")}
+    return {f.name: int(getattr(s, f.name)) for f in dataclasses.fields(s)
+            if f.type in (int, "int") and f.name not in HIGH_WATER}
 
 
 def run_cell(cell, seed: int, seconds: float, trace: bool, *,
@@ -188,7 +200,7 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, *,
 
     red = None
     if trace:
-        red = TR.reduce(TR.from_xspace(TR.find_xplane(tdir)), KERNELS)
+        red = TR.reduce(TR.from_xspace(TR.find_xplane(tdir)), spec.kernels())
         shutil.rmtree(tdir, ignore_errors=True)
         log("trace", window_s=red.window_s, busy_s=red.busy_s,
             kernel_s=red.kernel_s, kernel_calls=red.kernel_calls,

@@ -10,7 +10,7 @@ import importlib.util
 import json
 import os
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List
+from typing import Any, Callable, Dict, List, Optional
 
 BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH_DIR)
@@ -97,6 +97,27 @@ def kernel_counts(kernel: str, bench_dir: str = BENCH_DIR):
     ``bytes_moved(**shape)``."""
     return load_module(os.path.join(bench_dir, "kernels", kernel + ".py"),
                        "bench_kernel_" + kernel)
+
+
+def kernels(bench_dir: str = BENCH_DIR) -> List[str]:
+    """The kernels the trace reduction attributes: the stems of
+    ``bench/kernels/*.py``, one file for each kernel."""
+    d = os.path.join(bench_dir, "kernels")
+    return sorted(f[:-len(".py")] for f in os.listdir(d)
+                  if f.endswith(".py"))
+
+
+def expert_kernel(bench_dir: str = BENCH_DIR) -> Optional[str]:
+    """The kernel whose counts file declares ``TAKES = "experts"``: the
+    one that runs an instance's expert stacks.  None where no file
+    declares it; two that do are an error."""
+    named = [k for k in kernels(bench_dir)
+             if getattr(kernel_counts(k, bench_dir), "TAKES", None)
+             == "experts"]
+    if len(named) > 1:
+        raise ValueError(f"more than one kernel takes the expert stacks: "
+                         f"{named}")
+    return named[0] if named else None
 
 
 def reference_module(config: Dict[str, Any], bench_dir: str = BENCH_DIR):
