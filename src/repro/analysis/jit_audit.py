@@ -404,10 +404,10 @@ def default_workload(engine) -> List[str]:
 
 
 def _install(engine) -> Dict[str, JitCallRecorder]:
-    recs = {"_insert": JitCallRecorder("_insert", engine._insert),
-            "_decode": JitCallRecorder("_decode", engine._decode)}
-    engine._insert = recs["_insert"]
-    engine._decode = recs["_decode"]
+    recs = {n: JitCallRecorder(n, getattr(engine, n))
+            for n in ("_insert", "_decode", "_next_tok")}
+    for n, rec in recs.items():
+        setattr(engine, n, rec)
     if getattr(engine, "_seed", None) is not None:
         recs["_seed"] = JitCallRecorder("_seed", engine._seed)
         engine._seed = recs["_seed"]
@@ -423,8 +423,8 @@ def _install(engine) -> Dict[str, JitCallRecorder]:
 
 
 def _restore(engine, recs: Dict[str, JitCallRecorder]) -> None:
-    engine._insert = recs["_insert"].fn
-    engine._decode = recs["_decode"].fn
+    for n in ("_insert", "_decode", "_next_tok"):
+        setattr(engine, n, recs[n].fn)
     if "_seed" in recs:
         engine._seed = recs["_seed"].fn
     for b in list(engine._prefill):

@@ -23,21 +23,30 @@ The engine is an async core with three entry points:
                     decoding) and never touch a slot; finished prompts
                     short-circuit through the result cache.
   ``step()``        one engine tick: admit a batch into free slots
-                    (one bucketed prefill + one batched insert), run one
-                    vmapped decode step for all slots, retire rows that
-                    hit EOS / max_new.  Returns requests finished this
+                    (one bucketed prefill + one batched insert), launch
+                    one decode step for all slots, and pull the tokens
+                    of the step launched the tick before.  Returns the
+                    requests whose last token reached the host this
                     tick — callers may keep ``submit()``-ing between
                     ticks while decode is in flight.
-  ``drain()``       tick until queue and slots are empty.
+  ``drain()``       tick until queue, slots and launched steps are empty.
+
+The decode loop keeps **one step in flight**: step t+1 is launched from
+step t's tokens on the device (a jitted merge puts the first tokens of
+rows admitted in between over them), and step t's tokens are pulled
+while step t+1 runs, so the device never waits for the host between two
+steps.  A row that reaches ``max_new`` or the cache's end with step t
+frees its slot in the tick that launched step t, as a serial loop
+would, and is returned one tick later; a row that emits EOS is seen one
+step late, and the one token decoded past it is dropped.
 
 ``step()`` is internally split into ``step_begin()`` (admit + launch
 the tick's decode, without blocking on its result) and
-``step_finish()`` (block, retire).  A multi-device scheduler uses the
-split directly: it calls ``step_begin()`` on every engine first —
-XLA dispatch is asynchronous, so decode steps of engines **placed on
-distinct devices** execute concurrently — and only then collects with
-``step_finish()``.  ``step() == step_finish(step_begin())``, so the
-serial path is unchanged.
+``step_finish()`` (pull the earlier step, retire).  A multi-device
+scheduler uses the split directly: it calls ``step_begin()`` on every
+engine first — XLA dispatch is asynchronous, so decode steps of engines
+**placed on distinct devices** execute concurrently — and only then
+collects with ``step_finish()``.  ``step() == step_finish(step_begin())``.
 
 Placement: ``Engine(..., device=d)`` commits the params (and all slot
 state) to one jax device, so a ``ModelPool`` can spread its resident
@@ -71,7 +80,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, NamedTuple, Optional, Sequence
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +117,7 @@ class EngineStats:
     prefill_tokens: int = 0      # padded prompt tokens actually prefilled
     prefill_tokens_saved: int = 0  # prefix tokens NOT re-prefilled per row
     host_syncs: int = 0          # device->host pulls (2 per admission, 2 per decode)
+    pipelined_steps: int = 0     # decode steps launched before the last one was pulled
     backend: str = ""            # resolved KernelBackend ("reference"/"pallas")
     kv_blocks_in_use: int = 0    # peak KV blocks reachable (paged layout)
     kv_blocks_shared: int = 0    # peak blocks aliased by >1 slot (paged)
@@ -141,6 +151,22 @@ class StepPending(NamedTuple):
     slots), so schedulers can tell real in-flight work from a no-op."""
     finished: List["Request"]
     nxt: Any
+
+
+class _Launched(NamedTuple):
+    """A decode step whose tokens the host has not pulled yet: its
+    output arrays, and ``(slot, request, last)`` for every row it
+    decoded, ``last`` when the step gives the row its final token by
+    ``max_new`` or the cache's end."""
+    nxt: Any
+    conf: Any
+    rows: List[Tuple[int, Request, bool]]
+
+
+# what a slot feeds the next decode step (``Engine._feed``): the first
+# token of a row admitted since the last launch (>= 0), or
+_KEEP = -1      # the token it was fed last (an idle slot)
+_CARRY = -2     # the last launched step's output for it
 
 
 class Engine:
@@ -249,8 +275,27 @@ class Engine:
         self._active: Dict[int, Request] = {}           # slot -> request
         self._leaders: Dict[tuple, Request] = {}        # in-flight dedup
         self._followers: Dict[tuple, List[Request]] = {}
-        self._cur_tok = np.zeros((self.slots,), np.int32)
+        # positions live on the host (+1 per step per live slot); tokens
+        # live on the device: _cur_tok holds what the last launched step
+        # was fed, and _last_nxt that step's output
         self._cur_pos = np.zeros((self.slots,), np.int32)
+        self._n_out = np.zeros((self.slots,), np.int32)  # tokens once pulled
+        self._feed = np.full((self.slots,), _KEEP, np.int32)
+        tok_sharding = (None if mesh is None else jax.sharding.NamedSharding(
+            mesh, jax.sharding.PartitionSpec()))
+        self._cur_tok = jax.device_put(jnp.zeros((self.slots,), jnp.int32),
+                                       tok_sharding or device)
+        self._last_nxt = self._cur_tok
+        self._unpulled: List[_Launched] = []
+
+        # the next decode step's tokens, made on the device from the last
+        # step's tokens and output: no host round trip between two steps
+        def next_tokens(fed, nxt, feed):
+            return jnp.where(feed >= 0, feed,
+                             jnp.where(feed == _CARRY, nxt, fed))
+
+        self._next_tok = (jax.jit(next_tokens) if tok_sharding is None else
+                          jax.jit(next_tokens, out_shardings=tok_sharding))
         self._key = jax.random.PRNGKey(self.sampling.seed)
         # PRNG stream positions are private state, NOT stats: resetting
         # engine.stats must never replay sampled tokens
@@ -394,7 +439,9 @@ class Engine:
         """Device mirror of the allocator's block tables, refreshed only
         when host-side bookkeeping changed since the last decode."""
         if self._tables_dirty or self._tables_dev is None:
-            t = jnp.asarray(self._alloc.tables)
+            # a copy: the allocator edits its tables while a step that
+            # reads this upload may still be in flight
+            t = jnp.asarray(self._alloc.tables.copy())
             if self.device is not None:
                 t = jax.device_put(t, self.device)
             self._tables_dev = t
@@ -558,6 +605,12 @@ class Engine:
             if rec:
                 rec.attrs["kv_lens"] = [int(self._cur_pos[s]) + 1
                                         for s in self._active]
+            # uploads of copies: the host edits _feed and _cur_pos while
+            # this step may still be reading them
+            toks = self._next_tok(self._cur_tok, self._last_nxt,
+                                  jnp.asarray(self._feed.copy()))
+            pos = jnp.asarray(self._cur_pos.copy())
+            ctr = jnp.int32(self._decode_ctr)
             if self._paged:
                 used, sh = self._alloc.stats()
                 self.stats.kv_blocks_in_use = max(
@@ -566,13 +619,22 @@ class Engine:
                     self.stats.kv_blocks_shared, sh)
                 nxt, conf, self._slot_state = self._decode(
                     self.params, self._slot_state, self._tables(),
-                    jnp.asarray(self._cur_tok), jnp.asarray(self._cur_pos),
-                    jnp.int32(self._decode_ctr))
+                    toks, pos, ctr)
             else:
                 nxt, conf, self._slot_state = self._decode(
-                    self.params, self._slot_state,
-                    jnp.asarray(self._cur_tok), jnp.asarray(self._cur_pos),
-                    jnp.int32(self._decode_ctr))
+                    self.params, self._slot_state, toks, pos, ctr)
+            # the pull a tick from now finds the bytes on the host
+            nxt.copy_to_host_async()
+            conf.copy_to_host_async()
+        if self._unpulled:
+            self.stats.pipelined_steps += 1
+        self._unpulled.append(_Launched(nxt, conf, [
+            (s, r, int(self._n_out[s]) + 1 >= r.max_new
+             or int(self._cur_pos[s]) + 1 >= self.max_len - 1)
+            for s, r in self._active.items()]))
+        self._cur_tok, self._last_nxt = toks, nxt
+        self._feed[:] = _KEEP
+        self._feed[list(self._active)] = _CARRY
         self._decode_ctr += 1
         self.stats.decode_steps += 1
         self.stats.busy_slot_steps += len(self._active)
@@ -674,7 +736,8 @@ class Engine:
                     finished.extend(self._retire(r))
                     continue
                 self._active[s] = r
-                self._cur_tok[s] = t0
+                self._feed[s] = t0
+                self._n_out[s] = 1
                 self._cur_pos[s] = plen + int(lens[i])
             if admit:
                 admit.attrs.update(
@@ -685,41 +748,62 @@ class Engine:
         return finished
 
     def step_finish(self, pending: StepPending) -> List[Request]:
-        """Second half of a tick: block on the launched decode, then
-        retire/advance every active slot.  Returns all requests that
-        finished during the whole tick (admission-retired + decoded)."""
-        finished, nxt = pending
-        if nxt is None:
-            return finished
-        nxt, conf = nxt
+        """Second half of a tick: pull the tokens of the step launched
+        the tick before (of every launched step, on a tick that launched
+        none), retire the rows they end, and advance the slots of the
+        step just launched.  Returns the requests finished at this
+        tick's admission and those whose last token was pulled."""
+        finished, launched = pending
+        keep = 0 if launched is None else 1
+        while len(self._unpulled) > keep:
+            finished.extend(self._pull(self._unpulled.pop(0)))
+        if launched is not None:
+            # positions and slots move as the step's tokens are known to
+            # land: a row ending with this step frees its slot now
+            for s, r, last in self._unpulled[-1].rows:
+                if self._active.get(s) is not r:
+                    continue            # ended on EOS in the pull above
+                self._cur_pos[s] += 1
+                self._n_out[s] += 1
+                if last:
+                    del self._active[s]
+                    self._release_slot(s)
+        return finished
+
+    def _pull(self, step: _Launched) -> List[Request]:
+        """Block on one launched step's tokens and append them to their
+        rows; returns the rows whose last token this was."""
         with tracing.span("engine.pull"):
-            nxt = np.asarray(nxt)
-            conf = np.asarray(conf)
+            nxt = np.asarray(step.nxt)
+            conf = np.asarray(step.conf)
         self.stats.host_syncs += 2
-        # --- retire / advance ---
+        finished: List[Request] = []
         with tracing.span("engine.retire") as rec:
-            n0 = len(finished)
-            for s in list(self._active):
-                r = self._active[s]
+            for s, r, last in step.rows:
+                if r.done:
+                    # ended on EOS a step before: this token was decoded
+                    # past its end and is dropped
+                    continue
                 t = int(nxt[s])
                 r.out_ids.append(t)
                 r.confidence = min(r.confidence, float(conf[s]))
-                self._cur_tok[s] = t
-                self._cur_pos[s] += 1
-                if t == self.tok.EOS or len(r.out_ids) >= r.max_new \
-                        or self._cur_pos[s] >= self.max_len - 1:
+                if t == self.tok.EOS and not last:
+                    # the one end the host learns late: the slot ran one
+                    # step past it, and frees now
                     del self._active[s]
                     self._release_slot(s)
+                if t == self.tok.EOS or last:
                     finished.extend(self._retire(r))
             if rec:
-                rec.attrs["rows"] = len(finished) - n0
+                rec.attrs["rows"] = len(finished)
         return finished
 
     def has_work(self) -> bool:
-        """True while any request is queued or actively decoding — the
-        scheduler's cheap should-I-tick-this-engine probe (a bare
-        ``step()`` on an idle engine would still allocate slot state)."""
-        return bool(len(self.batcher) or self._active)
+        """True while any request is queued or decoding, or a launched
+        step is not pulled yet — the scheduler's cheap
+        should-I-tick-this-engine probe (a bare ``step()`` on an idle
+        engine would still allocate slot state)."""
+        return bool(len(self.batcher) or self._active or self._unpulled)
 
     def drain(self) -> List[Request]:
         """Tick until every queued and active request has finished."""
@@ -735,7 +819,8 @@ class Engine:
         and the jit-cache accounting in tests keys on.  Bucket-laddered
         targets are suffixed ``[bucket]``."""
         out: Dict[str, object] = {"_insert": self._insert,
-                                  "_decode": self._decode}
+                                  "_decode": self._decode,
+                                  "_next_tok": self._next_tok}
         if self._seed is not None:
             out["_seed"] = self._seed
         for b, fn in self._prefill.items():

@@ -46,6 +46,7 @@ class TestCleanEngine:
         assert any("_prefill_from[" in n for n in report.cache_stats)
         assert "_decode" in report.cache_stats
         assert "_insert" in report.cache_stats
+        assert "_next_tok" in report.cache_stats
 
     def test_budget_extracted(self, audited):
         _, report = audited
@@ -145,7 +146,7 @@ class TestJitTargets:
         cfg, params = tiny_dense
         engine = Engine(params, cfg)
         names = set(engine.jit_targets())
-        assert {"_insert", "_decode"} <= names
+        assert {"_insert", "_decode", "_next_tok"} <= names
         assert {n for n in names if n.startswith("_prefill[")} == {
             f"_prefill[{b}]" for b in engine.buckets}
         # prefix cache enabled by default -> the seeded ladder exists

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import ModelConfig
+from repro.core.compressed import kernel_backend
 from repro.models import api
 from repro.olap import operators as OPS
 from repro.olap.table import Table
@@ -273,3 +274,177 @@ class TestStreamingOperators:
         # A and B were admitted into slots together: some decode steps
         # ran 2 busy slots (with the stall bug, A always decoded alone)
         assert eng.stats.busy_slot_steps > eng.stats.decode_steps
+
+
+def serial_loop(eng, texts, max_news, *, prefix=None):
+    """The decode loop with no step in flight, the reference the
+    engine's loop must match: each step launched from tokens the host
+    has pulled, and pulled in the tick that launched it.  It shares the
+    engine's admission and programs, so the two differ in the loop
+    alone."""
+    reqs = [eng.submit(t, max_new=m, prefix=prefix)
+            for t, m in zip(texts, max_news)]
+    eng._init_slots()
+    tok = np.zeros(eng.slots, np.int32)
+    with kernel_backend(eng.backend):
+        while len(eng.batcher) or eng._active:
+            before = set(eng._active)
+            free = [s for s in range(eng.slots) if s not in eng._active]
+            take = eng.batcher.take(len(free))
+            if take:
+                eng._admit(take, free)
+            for s in set(eng._active) - before:
+                tok[s] = eng._active[s].out_ids[0]
+            if not eng._active:
+                continue
+            tables = (eng._tables(),) if eng._paged else ()
+            nxt, conf, eng._slot_state = eng._decode(
+                eng.params, eng._slot_state, *tables, jnp.asarray(tok),
+                jnp.asarray(eng._cur_pos), jnp.int32(eng._decode_ctr))
+            eng._decode_ctr += 1
+            nxt, conf = np.asarray(nxt), np.asarray(conf)
+            for s, r in list(eng._active.items()):
+                t = int(nxt[s])
+                r.out_ids.append(t)
+                r.confidence = min(r.confidence, float(conf[s]))
+                tok[s] = t
+                eng._cur_pos[s] += 1
+                if t == eng.tok.EOS or len(r.out_ids) >= r.max_new \
+                        or eng._cur_pos[s] >= eng.max_len - 1:
+                    del eng._active[s]
+                    eng._release_slot(s)
+                    eng._retire(r)
+    return reqs
+
+
+def _rows(reqs):
+    return [(r.out_ids, r.text, r.confidence) for r in reqs]
+
+
+class TestOneStepInFlight:
+    """The engine launches step t+1 from step t's tokens on the device
+    and pulls step t's tokens while step t+1 runs."""
+
+    TPL = "fix this value: "
+    TEXTS = [f"row {i} {'xy' * (i % 4)}" for i in range(10)]
+    # max_new 1 ends at admission, 2 after one step, 16 runs long: with
+    # 3 slots, admissions land in slots that max_new freed that tick
+    MAX_NEW = [(1, 2, 16)[i % 3] for i in range(10)]
+
+    @pytest.mark.parametrize("layout", ["paged", "contiguous"])
+    @pytest.mark.parametrize("sampling", [
+        None, SamplingConfig(temperature=0.9, top_k=8, seed=5)],
+        ids=["greedy", "sampled"])
+    def test_same_rows_as_the_serial_loop(self, tiny, layout, sampling):
+        cfg, params = tiny
+        mk = lambda: Engine(params, cfg, slots=3, max_len=64,
+                            buckets=(16, 32), use_result_cache=False,
+                            kv_layout=layout, sampling=sampling)
+        eng, ref = mk(), mk()
+        assert eng._paged == (layout == "paged")
+        got = [eng.submit(self.TPL + t, max_new=m, prefix=self.TPL)
+               for t, m in zip(self.TEXTS, self.MAX_NEW)]
+        eng.drain()
+        want = serial_loop(ref, [self.TPL + t for t in self.TEXTS],
+                           self.MAX_NEW, prefix=self.TPL)
+        assert _rows(got) == _rows(want)
+        assert [len(r.out_ids) for r in got] == self.MAX_NEW
+        assert eng.stats.prefix_hits == ref.stats.prefix_hits > 0
+        assert eng._decode_ctr == ref._decode_ctr == eng.stats.decode_steps
+        # a slot-step per decoded token past the first, nothing more
+        assert eng.stats.busy_slot_steps == sum(m - 1 for m in self.MAX_NEW)
+
+    def test_rows_match_reference_greedy_decode(self, tiny):
+        """More rows than slots, 16 new tokens each, against the model's
+        own greedy decode of each row alone."""
+        from repro.core.policy import greedy_decode
+        cfg, params = tiny
+        eng = Engine(params, cfg, slots=2, max_len=64, buckets=(16,),
+                     use_result_cache=False)
+        texts = ["check me", "and me", "third", "fourth row"]
+        outs = eng.generate(texts, max_new=16)
+        for text, out in zip(texts, outs):
+            ids = eng.tok.encode(text, bos=True) + [eng.tok.SEP]
+            toks = np.zeros((1, 16), np.int32)
+            toks[0, :len(ids)] = ids
+            ref = greedy_decode(params, cfg, jnp.asarray(toks), 16,
+                                lengths=jnp.asarray([len(ids)]))
+            assert out == eng.tok.decode(
+                [t for t in np.asarray(ref)[0] if t != eng.tok.EOS])
+
+    def test_rows_return_when_their_last_token_is_pulled(self, tiny):
+        """A row ending with step t frees its slot in the tick that
+        launched step t and comes back a tick later, from a tick that
+        launches nothing when no row is left to decode."""
+        cfg, params = tiny
+        eng = Engine(params, cfg, slots=2, max_len=64, buckets=(16,),
+                     use_result_cache=False)
+        a, b = (eng.submit(t, max_new=2) for t in ("first", "second"))
+        assert eng.step() == []            # admitted, step 0 in flight
+        assert not eng._active and eng.has_work()
+        assert not a.done and len(a.out_ids) == 1
+        handle = eng.step_begin()           # nothing left to decode
+        assert handle.nxt is None
+        assert eng.step_finish(handle) == [a, b]
+        assert [len(r.out_ids) for r in (a, b)] == [2, 2]
+        assert not eng.has_work() and eng.step() == []
+        assert eng.stats.decode_steps == 1
+        assert eng.stats.host_syncs == 2 * 1 + 2 * eng.stats.prefills
+
+    def test_pipelined_steps_count_steps_launched_before_a_pull(self, tiny):
+        """Every step is launched with the one before it unpulled, but
+        the first after the engine emptied."""
+        cfg, params = tiny
+        eng = Engine(params, cfg, slots=2, max_len=64, buckets=(16,),
+                     use_result_cache=False)
+        eng.generate([f"row {i}" for i in range(5)], max_new=4)
+        st = eng.stats
+        assert st.decode_steps >= 6
+        assert st.pipelined_steps == st.decode_steps - 1
+        eng.generate(["again", "and again"], max_new=3)
+        assert st.pipelined_steps == st.decode_steps - 2
+
+    def test_eos_during_decode_is_seen_one_step_late(self, tiny):
+        """Slot 0 emits EOS at the engine's third decode step.  The row
+        ends there with nothing after EOS, the token decoded past it is
+        dropped (one extra busy slot-step), the slot takes the next
+        queued row, and drain returns every row."""
+        from repro.serving import engine as E
+        cfg, params = tiny
+        mk = lambda: Engine(params, cfg, slots=2, max_len=64,
+                            buckets=(16,), use_result_cache=False)
+        eng = mk()
+        texts = ["ends early", "runs on", "third row", "fourth row"]
+        max_new = [8, 8, 4, 4]
+        at = jax.random.fold_in(eng._key, 2)
+        orig = E.sample
+
+        def forced(logits, key, **kw):
+            tok = orig(logits, key, **kw)
+            if key is None:                 # greedy admission sample
+                return tok
+            hit = jnp.all(key == at) & (jnp.arange(tok.shape[0]) == 0)
+            return jnp.where(hit, eng.tok.EOS, tok)
+
+        E.sample = forced
+        try:
+            reqs = [eng.submit(t, max_new=m)
+                    for t, m in zip(texts, max_new)]
+            done, slot_of = [], {}
+            while eng.has_work():
+                handle = eng.step_begin()
+                for s, r in eng._active.items():
+                    slot_of.setdefault(r.rid, s)
+                done += eng.step_finish(handle)
+        finally:
+            E.sample = orig
+        early = reqs[0]
+        assert early.out_ids[-1] == eng.tok.EOS and len(early.out_ids) == 4
+        assert early.text == eng.tok.decode(early.out_ids[:-1])
+        assert slot_of[early.rid] == 0 and slot_of[reqs[2].rid] == 0
+        assert sorted(r.rid for r in done) == [r.rid for r in reqs]
+        assert [len(r.out_ids) for r in reqs[1:]] == max_new[1:]
+        assert eng.stats.busy_slot_steps == 1 + sum(
+            len(r.out_ids) - 1 for r in reqs)
+        # the row beside it decodes as it would with no EOS at all
+        assert reqs[1].text == mk().generate(texts[:2], max_new=8)[1]

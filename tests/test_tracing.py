@@ -1,7 +1,11 @@
 """The in-program span recorder (``repro.tracing``) and the spans and
 counter the serving engine, the scheduler and the recipe search feed
 it."""
+import dataclasses
+import importlib.util
+import os
 import time
+from types import SimpleNamespace
 
 import jax
 import jax.numpy as jnp
@@ -106,6 +110,30 @@ def test_host_syncs_count_two_per_admission_and_decode(tiny_dense):
     st = eng.stats
     assert st.decode_steps and st.prefills >= 3
     assert st.host_syncs == 2 * st.decode_steps + 2 * st.prefills
+
+
+def test_pipelined_share_reads_the_window_counters(tiny_dense):
+    """``bench/metrics/pipelined_share.py`` on a context as the harness
+    builds it (the window's integer ``EngineStats``): every decode step
+    but the first was launched before the one before it was pulled; a
+    program without the counter reads nothing."""
+    path = os.path.join(os.path.dirname(__file__), os.pardir, "bench",
+                        "metrics", "pipelined_share.py")
+    spec = importlib.util.spec_from_file_location("pipelined_share", path)
+    reader = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reader)
+    eng = _engine(tiny_dense, use_prefix_cache=False)
+    eng.generate([f"row {i} abc" for i in range(5)], max_new=4)
+    stats = {f.name: getattr(eng.stats, f.name)
+             for f in dataclasses.fields(eng.stats) if f.type in (int, "int")}
+    steps = stats["decode_steps"]
+    assert steps > 1 and stats["pipelined_steps"] == steps - 1
+    ctx = SimpleNamespace(engine_stats=stats)
+    assert reader.read(ctx) == pytest.approx(100.0 * (steps - 1) / steps)
+    del stats["pipelined_steps"]
+    assert reader.read(ctx) is None
+    assert reader.read(SimpleNamespace(engine_stats={
+        "decode_steps": 0, "pipelined_steps": 0})) is None
 
 
 def test_engine_spans_nest_and_carry_what_was_admitted(tiny_dense,
